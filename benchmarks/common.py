@@ -31,6 +31,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.obs import MetricsRegistry  # noqa: E402
 
 from repro.core import run_scheme  # noqa: E402
@@ -73,7 +74,9 @@ def setup_experiment(
 
     Returns ``(global_params, telemetry, local_train_fn, eval_fn,
     client_params)`` (client_params is None for homogeneous runs).
+    Turns on the persistent compile cache (repro.compile_cache) first.
     """
+    enable_compile_cache()
     train, test = make_dataset(dataset, num_train=num_train,
                                num_test=num_test, seed=seed)
     parts = PARTITIONS[partition](train, num_clients, seed=seed)

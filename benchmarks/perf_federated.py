@@ -215,31 +215,31 @@ def smoke(clients: int = 8, rounds: int = 2, rounds_per_dispatch: int = 2
 
 
 def sharded_ab(clients_list=(256, 1024), rounds: int = 6) -> dict:
-    """Sharded-vs-fused scaling curve on the VISIBLE device mesh.
+    """Sharded-vs-fused scaling curve on the VISIBLE device mesh, in this
+    process.
 
     Runs the same homogeneous FedDD simulation as the per-round ``fused``
     mode and the client-sharded ``sharded`` mode (ProtocolConfig mesh=True
     -> ShardedRoundEngine over every visible device) and reports
     rounds/sec, the sharded speedup, and the scaling efficiency
-    (speedup / devices).  Meant to run under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` on CPU (or on
-    a real accelerator mesh); on a 1-device process the sharded mode
-    degenerates to shard_map overhead measurement (~2%).
+    (speedup / devices).  On a CPU run the devices are whatever
+    ``--xla_force_host_platform_device_count`` made (virtual devices
+    sharing the host's cores); on a 1-device process the sharded mode
+    degenerates to shard_map overhead measurement.
 
-    ``physical_parallelism`` records whether the host can actually run the
-    shard programs concurrently (cpu_count >= devices on the CPU backend);
-    the acceptance gate only binds where it is true — an 8-way virtual
-    mesh round-robining on one core measures dispatch serialization, not
-    the engine's scaling.
+    ``platform`` / ``device_kind`` name the devices measured, and
+    ``physical_parallelism`` is true only for accelerator devices: CPU
+    devices, virtual or not, are threads of one host process, and the
+    acceptance gate only binds on real parallel hardware.
     """
     import os
-    devices = jax.device_count()
-    cpus = os.cpu_count() or 1
-    physical = (jax.default_backend() != "cpu") or cpus >= devices
+    devices = jax.devices()
     out = {
-        "devices": devices,
-        "cpu_count": cpus,
-        "physical_parallelism": bool(physical),
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "devices": len(devices),
+        "cpu_count": os.cpu_count() or 1,
+        "physical_parallelism": devices[0].platform != "cpu",
         "clients": {},
     }
     for c in clients_list:
@@ -255,44 +255,20 @@ def sharded_ab(clients_list=(256, 1024), rounds: int = 6) -> dict:
             "fused_rounds_per_sec": per["fused"],
             "sharded_rounds_per_sec": per["sharded"],
             "sharded_speedup": speedup,
-            "scaling_efficiency": speedup / max(devices, 1),
+            "scaling_efficiency": speedup / len(devices),
         }
     return out
 
 
-def _sharded_subprocess(clients_list, rounds: int, devices: int = 8):
-    """Collect the sharded scaling curve in a child process with
-    ``devices`` virtual CPU devices (XLA fixes the device count at
-    import, so the parent cannot re-mesh itself)."""
-    import json
-    import os
-    import subprocess
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count={devices}"
-                        ).strip()
-    code = (
-        "import json\n"
-        "from benchmarks.perf_federated import sharded_ab\n"
-        f"print(json.dumps(sharded_ab({tuple(clients_list)!r}, "
-        f"rounds={rounds})))\n"
-    )
-    root = Path(__file__).resolve().parents[1]
-    env["PYTHONPATH"] = f"{root}/src:{root}"
-    proc = subprocess.run([sys.executable, "-c", code], env=env,
-                         capture_output=True, text=True, cwd=root,
-                         check=True)
-    return json.loads(proc.stdout.splitlines()[-1])
-
-
 def bench_json(out_dir: Path, *, clients=(16, 64), rounds: int = 6,
                rounds_per_dispatch: int = 8,
-               sharded_clients=(256, 1024), mesh_devices: int = 8) -> Path:
+               sharded_clients=(256, 1024)) -> Path:
     """Machine-readable perf trajectory: rounds/sec per execution path at
     each fleet size -> results/BENCH_round_engine.json (CI artifact, the
     regression baseline future PRs compare against).  The ``sharded``
-    section is the client-sharded scaling curve, collected in a child
-    process carrying an ``mesh_devices``-way virtual CPU mesh."""
+    section is the client-sharded scaling curve over the devices this
+    process sees (:func:`sharded_ab`); no child process is started, so
+    the run never needs a device its own process already holds."""
     rounds_per_dispatch = min(rounds_per_dispatch, rounds)  # effective K
     payload = {
         "bench": "round_engine",
@@ -310,8 +286,7 @@ def bench_json(out_dir: Path, *, clients=(16, 64), rounds: int = 6,
                    "sec_per_round": wall / rounds}
             for mode, (_, wall, rps) in results.items()
         }
-    payload["sharded"] = _sharded_subprocess(sharded_clients, rounds,
-                                             devices=mesh_devices)
+    payload["sharded"] = sharded_ab(sharded_clients, rounds)
     biggest = str(max(clients))
     per = payload["clients"][biggest]
     speedup = (per["scanned"]["rounds_per_sec"]
@@ -369,7 +344,7 @@ def main():
     ap.add_argument("--json", action="store_true",
                     help="write results/BENCH_round_engine.json "
                          "(rounds/sec per path at 16/64 clients + the "
-                         "sharded scaling curve on an 8-way virtual mesh)")
+                         "sharded scaling curve on the visible devices)")
     ap.add_argument("--sharded", action="store_true",
                     help="print the sharded-vs-fused scaling curve on the "
                          "VISIBLE devices (run under XLA_FLAGS="

@@ -143,6 +143,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import jax  # noqa: E402
 
 from repro.comm import CommConfig  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core import run_scheme  # noqa: E402
 from repro.data import (label_coverage_score, make_dataset,  # noqa: E402
                         partition_noniid_b)
@@ -212,6 +213,7 @@ def main():
                     help="wrap host spans in jax.profiler trace "
                          "annotations (implies observability on)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     train, test = make_dataset("mnist", num_train=6000, num_test=1500)
     parts = partition_noniid_b(train, args.clients, seed=0)

@@ -25,6 +25,11 @@ Writes ``full.jsonl`` / ``crash.jsonl`` / ``resume.jsonl`` run logs, the
 surviving ``ck.npz`` snapshot (+ sidecar), and a ``summary.json`` with
 the digests and verdict into the output dir (uploaded as a CI
 artifact); exits non-zero on any contract violation.
+
+CI only: the children run with ``JAX_PLATFORMS=cpu`` unless the caller
+sets that variable, because the digests are the CPU lane's contract.
+This script is not a chip entry point (``chip_smoke.py`` is), and no
+entry point pins its children, or itself, to the CPU.
 """
 
 from __future__ import annotations
@@ -119,7 +124,7 @@ def _child(mode: str, ckpt_path: str, log_path: str) -> None:
 
 def _spawn(mode: str, out_dir: Path) -> subprocess.CompletedProcess:
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env.setdefault("JAX_PLATFORMS", "cpu")   # CI-only setting, see above
     env["PYTHONPATH"] = (str(REPO / "src") + os.pathsep
                          + env.get("PYTHONPATH", "")).rstrip(os.pathsep)
     return subprocess.run(

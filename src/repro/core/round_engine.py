@@ -62,7 +62,6 @@ from typing import List, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 
 from repro.comm import codecs as wire_codecs
 from repro.comm import quantize as wire_quant
@@ -702,14 +701,14 @@ def _sharded_step_fn(mesh, sel_cfg: selection.SelectionConfig,
                     new_global, stacked_new, masks)
         return new_clients, new_global, density, wire_oh, overflow
 
-    # check_rep=False: the replicated outputs (new_global, overflow) are
+    # check_vma=False: the replicated outputs (new_global, overflow) are
     # replicated BY CONSTRUCTION — psum / identical all_gather+scatter on
     # every shard — but the static replication checker cannot prove it
     # through the scatter-adds of the sparse path.
-    fn = shard_map(body, mesh,
-                   in_specs=(p_c, p_c, p_r, p_c, p_c, p_c, p_r),
-                   out_specs=(p_c, p_r, p_c, p_c, p_r),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(p_c, p_c, p_r, p_c, p_c, p_c, p_r),
+                       out_specs=(p_c, p_r, p_c, p_c, p_r),
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -993,11 +992,11 @@ def _sharded_grouped_round_step(groups: Tuple[GroupBatch, ...],
             return masks, dens, oh, tuple(nums), tuple(dens_l)
 
         w_rows = weights_ext[g.indices]
-        masks, dens, oh, nums, dens_l = shard_map(
-            body, mesh,
+        masks, dens, oh, nums, dens_l = jax.shard_map(
+            body, mesh=mesh,
             in_specs=(p_c, p_c, p_c, p_c, p_c, p_r, p_r),
             out_specs=(p_c, p_c, p_c, p_r, p_r),
-            check_rep=False)(g.stacked_old, g.stacked_new,
+            check_vma=False)(g.stacked_old, g.stacked_new,
                              g.dropout, w_rows, g.indices, g.coverage,
                              rng)
         num_tot = [a + b for a, b in zip(num_tot, nums)]

@@ -1,12 +1,15 @@
 """Pallas TPU kernel: fused per-channel importance (FedDD Eq. (20)).
 
 Tiling: grid (C/BC, F/BF); W_old/W_new blocks (BC, BF) stream HBM->VMEM; the
-(BC,) partial sum-of-squares accumulates in the output block, which is
+(BC, 1) partial sum-of-squares accumulates in the output block, which is
 revisited across the fan-in grid axis (output index_map ignores j, so the
 block stays VMEM-resident over the minor grid dimension — the standard TPU
-reduction pattern).  MXU is not involved (elementwise + row reduce): the
-kernel is memory-bound by design, its value is fusing three elementwise ops
-+ reduction into one HBM pass over two weight tensors.
+reduction pattern).  The output is a (C, 1) column rather than a (C,)
+vector: Mosaic tiles a 1-D block differently from XLA's layout of the
+same array (T(256) vs T(1024)) and refuses it on TPU.  MXU is not
+involved (elementwise + row reduce): the kernel is memory-bound by
+design, its value is fusing three elementwise ops + reduction into one
+HBM pass over two weight tensors.
 
 Block sizes default to (256, 512): 2 * 256*512*4B = 1 MiB of VMEM for the
 inputs — comfortably within the ~16 MiB v5e VMEM budget while keeping the
@@ -41,7 +44,7 @@ def _importance_kernel(c: int, f: int, w_old_ref, w_new_ref, out_ref):
     dw = wn - wo
     denom = jnp.where(jnp.abs(wo) < EPS, jnp.where(wo < 0, -EPS, EPS), wo)
     imp = jnp.abs(dw * wn / denom)
-    partial = jnp.sum(imp * imp, axis=1)
+    partial = jnp.sum(imp * imp, axis=1, keepdims=True)
 
     @pl.when(j == 0)
     def _init():
@@ -68,7 +71,7 @@ def channel_importance_sumsq(w_old: jax.Array, w_new: jax.Array, *,
             pl.BlockSpec((bc, bf), lambda i, j: (i, j)),
             pl.BlockSpec((bc, bf), lambda i, j: (i, j)),
         ],
-        out_specs=pl.BlockSpec((bc,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((c,), jnp.float32),
+        out_specs=pl.BlockSpec((bc, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((c, 1), jnp.float32),
         interpret=interpret,
-    )(w_old, w_new)
+    )(w_old, w_new).reshape(c)

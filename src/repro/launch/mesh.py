@@ -51,23 +51,28 @@ def make_host_mesh(data: int = 1, model: int = 1):
 def make_client_mesh(num_devices: int | None = None):
     """1-D ``clients`` mesh for the client-sharded round engines.
 
-    Uses up to ``num_devices`` local devices (all of them by default).
-    This is the mesh :class:`repro.core.round_engine.ShardedRoundEngine`
-    shards the fleet axis over; client counts need not divide the mesh —
-    the engine zero-pads the trailing shard.
+    Uses exactly ``num_devices`` local devices (all of them by default)
+    and raises when fewer exist: a mesh quietly narrowed to the devices
+    at hand would run, and pass, on a width nobody asked for.  This is
+    the mesh :class:`repro.core.round_engine.ShardedRoundEngine` shards
+    the fleet axis over; client counts need not divide the mesh — the
+    engine zero-pads the trailing shard.
     """
     devs = jax.devices()
-    k = len(devs) if num_devices is None else max(1, min(int(num_devices),
-                                                         len(devs)))
+    k = len(devs) if num_devices is None else int(num_devices)
+    if not 1 <= k <= len(devs):
+        raise ValueError(
+            f"a {k}-device clients mesh was asked for, but "
+            f"{len(devs)} {devs[0].platform} device(s) are visible")
     return jax.sharding.Mesh(np.asarray(devs[:k]), ("clients",))
 
 
 def resolve_client_mesh(mesh):
     """Normalise a ``ProtocolConfig.mesh`` value to a 1-D clients Mesh.
 
-    Accepts an int (device count → :func:`make_client_mesh`), ``True``
-    (all local devices), or an existing Mesh that carries a ``clients``
-    axis.
+    Accepts an int (device count → :func:`make_client_mesh`, which
+    raises when that many devices are not visible), ``True`` (all local
+    devices), or an existing Mesh that carries a ``clients`` axis.
     """
     if mesh is True:
         return make_client_mesh()

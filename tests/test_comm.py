@@ -219,6 +219,20 @@ def _trees_equal(a, b):
         jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)))
 
 
+def _assert_trees_close(a, b, rtol=1e-5, atol=1e-7):
+    """Engine vs reference loop: one fused jitted step against per-client
+    eager ops cannot be one compiled program, and under jax 0.9 XLA:CPU
+    sums the float32 Eq. (4) terms in a different order in the two (the
+    order also varies with the process's thread layout), so the learning
+    state agrees to a few float32 ulps at O(1) scale, not bit for bit.
+    An accounting or mask fault moves values by 1e-2 and more."""
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                   rtol=rtol, atol=atol)
+
+
 @pytest.mark.parametrize("scheme", ["feddd", "fedavg", "fedcs", "oort"])
 def test_default_comm_wire_equals_uploaded_all_paths(scheme):
     """dense codec + qbits=32: wire_bytes == uploaded_bytes bitwise and
@@ -280,14 +294,14 @@ def _overhead_of(rec, qbits):
 def test_sparse_codec_engine_matches_loop(codec, qbits):
     """Sparse codecs + lossless/cast values: the engine run reproduces
     the reference loop's measured overhead exactly (integer bytes) and
-    the learning state bit for bit (fp16 casts are order-independent);
+    the learning state to float32 ulps (:func:`_assert_trees_close`);
     byte totals agree to the pre-existing density-ulp tolerance."""
     params, tel = _fixture()
     kw = dict(rounds=4, a_server=0.6, h=3, seed=0,
               comm=CommConfig(codec=codec, qbits=qbits))
     loop = run_scheme("feddd", params, tel, _ltf, None, batched=False, **kw)
     eng = run_scheme("feddd", params, tel, _ltf, None, batched=True, **kw)
-    assert _trees_equal(loop.global_params, eng.global_params)
+    _assert_trees_close(loop.global_params, eng.global_params)
     for rl, re_ in zip(loop.history, eng.history):
         assert _overhead_of(rl, qbits) == _overhead_of(re_, qbits) > 0
         assert rl.wire_bytes == pytest.approx(re_.wire_bytes, rel=1e-6)
@@ -374,7 +388,7 @@ def test_sparse_codec_grouped_matches_loop():
                       client_params=clients, **kw)
     grp = run_scheme("feddd", full, tel, _ltf, None, batched=True,
                      client_params=clients, **kw)
-    assert _trees_equal(loop.global_params, grp.global_params)
+    _assert_trees_close(loop.global_params, grp.global_params)
     for rl, rg in zip(loop.history, grp.history):
         assert _overhead_of(rl, 32) == _overhead_of(rg, 32) > 0
         assert rl.wire_bytes == pytest.approx(rg.wire_bytes, rel=1e-6)

@@ -48,7 +48,8 @@ def test_importance_kernel_rank_axis(rank_shape, axis):
 
 
 @pytest.mark.parametrize("n,c,f", [(2, 8, 16), (4, 64, 128), (7, 100, 300),
-                                   (16, 33, 70), (32, 128, 256)])
+                                   (16, 33, 70), (32, 128, 256),
+                                   (13, 40, 600)])
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_sparse_agg_kernel_sweep(n, c, f, dtype):
     key = jax.random.PRNGKey(n * 1000 + c)
@@ -64,6 +65,31 @@ def test_sparse_agg_kernel_sweep(n, c, f, dtype):
                                atol=1e-4)
     np.testing.assert_allclose(np.asarray(den), np.asarray(wd),
                                rtol=3e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("n", [5, 13, 33])
+@pytest.mark.parametrize("shape", [(3, 3, 4, 24), (40, 130), (600,)])
+def test_sparse_agg_kernel_channel_mask(n, shape):
+    """A per-channel mask (size 1 on every axis but the last), as the
+    engine builds it, broadcast by the wrapper over a stack whose trailing
+    axes fold into rows; client counts off the 8-client slab exercise the
+    zeroed tail of the last slab."""
+    key = jax.random.PRNGKey(n + len(shape))
+    sw = jax.random.normal(key, (n, *shape))
+    sm = (jax.random.uniform(jax.random.fold_in(key, 1),
+                             (n,) + (1,) * (len(shape) - 1) + shape[-1:])
+          > 0.5).astype(jnp.float32)
+    wts = jax.random.uniform(jax.random.fold_in(key, 2), (n,)) + 0.5
+    num, den = agg_ops.masked_weighted_sum(sw, sm, wts)
+    lanes = shape[-1]
+    wn, wd = masked_weighted_sum_ref(
+        sw.reshape(n, -1, lanes),
+        jnp.broadcast_to(sm, sw.shape).reshape(n, -1, lanes), wts)
+    assert num.shape == den.shape == shape
+    np.testing.assert_allclose(np.asarray(num).reshape(wn.shape),
+                               np.asarray(wn), rtol=3e-5, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(den).reshape(wd.shape),
+                               np.asarray(wd), rtol=3e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("c,f", [(8, 16), (64, 128), (100, 37), (7, 7),

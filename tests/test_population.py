@@ -145,8 +145,13 @@ def test_identity_contract_scanned_path_bit_exact():
     """Same contract against the scanned driver: with a key-free trainer
     (the same arithmetic whether vmapped inside the lax.scan dispatch or
     run per client in the sim) the population-identity sim reproduces
-    FedDDServer's rounds_per_dispatch>1 path exactly — Eq. (12) clock,
-    jax-allocator dropout rates, losses, and global params."""
+    FedDDServer's rounds_per_dispatch>1 path — Eq. (12) clock and
+    jax-allocator dropout rates exactly, losses and global params to
+    float32 ulps.  The two cannot be one compiled program (population
+    runs always route through the sim, which does not scan), and under
+    jax 0.9 XLA:CPU reduces the trainer's loss inside the scan body in a
+    different order than the per-client call (round 1's mean loss moves
+    by ~4e-7 relative)."""
     n = 8
 
     def ltf(p, idx, key):
@@ -170,11 +175,14 @@ def test_identity_contract_scanned_path_bit_exact():
                   sim=SimConfig(policy="sync"),
                   rounds=7, a_server=0.6, h=3, seed=0, allocator="jax")
     for hs, hp in zip(scan.history, pop.history):
-        assert hs.mean_loss == hp.mean_loss
+        assert hs.mean_loss == pytest.approx(hp.mean_loss, rel=1e-5)
         assert hs.sim_time == hp.sim_time
         np.testing.assert_array_equal(np.asarray(hs.dropout_rates),
                                       np.asarray(hp.dropout_rates))
-    assert _trees_equal(scan.global_params, pop.global_params)
+    for x, y in zip(jax.tree_util.tree_leaves(scan.global_params),
+                    jax.tree_util.tree_leaves(pop.global_params)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                   rtol=1e-5, atol=1e-7)
 
 
 # --- churn: cohorts smaller than the population ------------------------------

@@ -2,7 +2,8 @@
 
 Contracts pinned here (see core/round_engine.py ShardedRoundEngine):
 
-* ``launch.mesh`` helpers clamp to divisors and resolve mesh specs;
+* ``launch.mesh`` helpers: the host mesh clamps to divisors, the clients
+  mesh raises past the visible devices, and mesh specs resolve;
 * on a 1-DEVICE mesh the sharded step is BIT-IDENTICAL to the
   single-device ``BatchedRoundEngine`` (psum over one device is the
   identity, masks/QDQ fold GLOBAL fleet ids, and the Eq. (4) partials are
@@ -73,6 +74,18 @@ def test_resolve_client_mesh_accepts_true_int_and_mesh():
     m_one = mesh_mod.resolve_client_mesh(1)
     assert m_one.devices.size == 1
     assert mesh_mod.resolve_client_mesh(m_one) is m_one
+
+
+@pytest.mark.parametrize("ask", [0, "visible+1", "visible*4"])
+def test_client_mesh_raises_past_visible_devices(ask):
+    """A mesh wider than the visible devices (or empty) raises instead of
+    quietly narrowing — mesh=4 on one device must not run on one."""
+    n = jax.device_count()
+    k = {"visible+1": n + 1, "visible*4": 4 * n}.get(ask, ask)
+    with pytest.raises(ValueError, match="device"):
+        mesh_mod.make_client_mesh(k)
+    with pytest.raises(ValueError, match="device"):
+        mesh_mod.resolve_client_mesh(k)
 
 
 def test_resolve_client_mesh_rejects_wrong_axis():
@@ -314,6 +327,17 @@ def test_protocol_mesh_one_bit_identical_to_engine_executor():
     for a, b in zip(jax.tree_util.tree_leaves(s0.global_params),
                     jax.tree_util.tree_leaves(s1.global_params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_protocol_mesh_wider_than_visible_devices_raises():
+    """ProtocolConfig(mesh=4) on fewer than 4 devices fails at run time
+    instead of running a narrower mesh."""
+    from repro.core.protocol import FedDDServer
+    cfg = ProtocolConfig(selection=SelectionConfig(), rounds=1, seed=0,
+                         mesh=4 * jax.device_count())
+    srv = FedDDServer(_gparams(), cfg, _telemetry())
+    with pytest.raises(ValueError, match="device"):
+        srv.run(batched_train_fn=_btrain)
 
 
 def test_protocol_config_mesh_validations():

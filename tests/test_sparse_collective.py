@@ -87,7 +87,6 @@ _ORACLE_PRELUDE = """
 import numpy as np
 import jax, jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.sparse_collective import (
     make_federated_numden_allreduce, sparse_numden_allreduce)
@@ -98,10 +97,10 @@ rng = np.random.default_rng(7)
 C, F = 8, 5
 
 def shard_reduce(fn, num, den):
-    wrapped = shard_map(fn, mesh,
-                        in_specs=(P("clients"), P("clients")),
-                        out_specs=(P(), P(), P()),
-                        check_rep=False)
+    wrapped = jax.shard_map(fn, mesh=mesh,
+                            in_specs=(P("clients"), P("clients")),
+                            out_specs=(P(), P(), P()),
+                            check_vma=False)
     return wrapped(num, den)
 
 def dense_oracle(num, den):
