@@ -86,10 +86,10 @@ degradation                 packet-loss / corruption injection, payload
                             model, bit for bit (tests/test_faults.py)
 observability (metrics,     **every executor** via ``ProtocolConfig(obs=
 span tracing, JSONL run     ObsConfig(...))`` (repro.obs): the driver builds
-logs, run-inspection CLI)   one recorder per run; host spans wrap each
-                            pipeline phase (allocate / local_train /
-                            engine_step / encode / aggregate /
-                            client_update / host_transfer / eval —
+logs, run-inspection CLI)   one recorder per run; host spans wrap every
+                            host moment of ``run`` (the vocabulary is
+                            ``repro.obs.PHASES``: fleet_stack, the round
+                            phases, round_records, fleet_unstack —
                             ``Recorder.span`` in the executors below), a
                             metrics registry accumulates round / byte /
                             failure totals, and every RoundRecord lands in
@@ -525,18 +525,13 @@ class _EngineExecutor(_RoundExecutor):
                     loss_dev = jnp.where(pvec, jnp.asarray(loss_dev),
                                          jnp.asarray(losses))
             else:
-                per_client = round_engine.unstack_pytree(self.stacked, n)
-                new_list: List[Params] = [None] * n
+                # baseline non-participants keep their stale state
                 loss_dev: List = [None] * n
-                for i, p_i in enumerate(per_client):
-                    if part[i]:
-                        p, l = self.local_train_fn(
-                            p_i, i, jax.random.fold_in(rk, i))
-                    else:       # baseline non-participant: stale state
-                        p, l = p_i, losses[i]
-                    new_list[i] = p
-                    loss_dev[i] = l
-                stacked_new = round_engine.stack_pytrees(new_list)
+                new_list = round_engine.train_clients(
+                    self.stacked, range(n), self.local_train_fn, rk, part,
+                    losses, loss_dev, obs)
+                with obs.span("group_stack"):
+                    stacked_new = round_engine.stack_pytrees(new_list)
         with obs.span("engine_step", round=t):
             out = self.engine.step(self.stacked, stacked_new,
                                    srv.global_params, d_used,
@@ -723,7 +718,8 @@ class _GroupedEngineExecutor(_RoundExecutor):
                 else srv._participants(losses))
         with obs.span("local_train", round=t):
             loss_dev = self.fleet.train(self.local_train_fn, rk, part,
-                                        losses, d_used, dense=dense)
+                                        losses, d_used, dense=dense,
+                                        obs=obs)
         with obs.span("engine_step", round=t):
             srv.global_params, densities, wire_oh = self.fleet.step(
                 srv.global_params, self.weights * part, rk,
@@ -1016,9 +1012,6 @@ class FedDDServer:
         full_bytes = float(np.sum(self.tel.model_bytes))
 
         kind = self._executor_kind(batched_train_fn)
-        executor = self._EXECUTORS[kind](self, local_train_fn,
-                                         batched_train_fn)
-
         if cfg.rounds_per_dispatch > 1:
             if kind != "engine":
                 raise ValueError(
@@ -1037,27 +1030,33 @@ class FedDDServer:
                     "at dispatch boundaries; use rounds_per_dispatch=1 "
                     "for per-round eval")
 
-        # --- crash-resume (repro.checkpoint): restore a snapshot before
-        # the loop, save one every checkpoint_every completed rounds.
-        # checkpoint_every=None and resume_from=None touch nothing.
-        start_t = 1
-        if cfg.resume_from:
-            from repro import checkpoint as ckpt_mod   # checkpoint -> core
-            st = ckpt_mod.load_run_state(
-                cfg.resume_from, self._snapshot_arrays(executor, losses))
-            losses = self._restore_arrays(executor, st.arrays)
-            history = st.history
-            sim_time = float(st.extra.get("sim_time", 0.0))
-            start_t = st.round + 1
-
         self.obs = obs_mod.make_recorder(
             cfg.obs, driver="protocol", scheme=cfg.scheme, executor=kind
             if cfg.rounds_per_dispatch == 1 else "scanned",
             clients=n, rounds=rounds)
         try:
+            with self.obs.span("fleet_stack"):
+                executor = self._EXECUTORS[kind](self, local_train_fn,
+                                                 batched_train_fn)
+
+            # --- crash-resume (repro.checkpoint): restore a snapshot
+            # before the loop, save one every checkpoint_every completed
+            # rounds.  checkpoint_every=None and resume_from=None touch
+            # nothing.
+            start_t = 1
+            if cfg.resume_from:
+                from repro import checkpoint as ckpt_mod  # checkpoint -> core
+                st = ckpt_mod.load_run_state(
+                    cfg.resume_from, self._snapshot_arrays(executor, losses))
+                losses = self._restore_arrays(executor, st.arrays)
+                history = st.history
+                sim_time = float(st.extra.get("sim_time", 0.0))
+                start_t = st.round + 1
+
             if cfg.rounds_per_dispatch > 1:
                 self._run_scanned(executor, rounds, history, full_bytes)
-                executor.finalize()
+                with self.obs.span("fleet_unstack"):
+                    executor.finalize()
                 return RunResult(history, self.global_params)
 
             for t in range(start_t, rounds + 1):
@@ -1074,30 +1073,38 @@ class FedDDServer:
                         alloc = self.allocate(np.maximum(losses, 1e-6))
                     self.dropout = alloc.dropout_rates
 
-                # --- simulated wall clock (paper Eq. (12))
-                sim_time, round_t, metrics, t_all = self._finish_round(
-                    rd.active, sim_time, eval_fn, d_used)
-                history.append(self._record(t, t0, sim_time, round_t,
-                                            losses, rd.uploaded_bytes,
-                                            rd.wire_bytes, full_bytes,
-                                            rd.active, rd.epsilon,
-                                            metrics))
-                if self.obs.active:
-                    self.obs.round(
-                        history[-1], path=kind, scheme=cfg.scheme,
-                        client_times=np.where(rd.active, t_all, np.nan))
-                if (cfg.checkpoint_every is not None
-                        and t % cfg.checkpoint_every == 0):
-                    from repro import checkpoint as ckpt_mod
-                    ckpt_mod.save_run_state(
-                        cfg.checkpoint_path,
-                        ckpt_mod.RunState(
-                            round=t,
-                            arrays=self._snapshot_arrays(executor, losses),
-                            history=history,
-                            extra={"sim_time": sim_time}))
+                metrics = None
+                if eval_fn:
+                    with self.obs.span("eval", round=t):
+                        metrics = eval_fn(self.global_params)
 
-            executor.finalize()
+                with self.obs.span("round_records", round=t):
+                    # --- simulated wall clock (paper Eq. (12))
+                    sim_time, round_t, t_all = self._clock(
+                        rd.active, sim_time, d_used)
+                    history.append(self._record(t, t0, sim_time, round_t,
+                                                losses, rd.uploaded_bytes,
+                                                rd.wire_bytes, full_bytes,
+                                                rd.active, rd.epsilon,
+                                                metrics))
+                    if self.obs.active:
+                        self.obs.round(
+                            history[-1], path=kind, scheme=cfg.scheme,
+                            client_times=np.where(rd.active, t_all, np.nan))
+                    if (cfg.checkpoint_every is not None
+                            and t % cfg.checkpoint_every == 0):
+                        from repro import checkpoint as ckpt_mod
+                        ckpt_mod.save_run_state(
+                            cfg.checkpoint_path,
+                            ckpt_mod.RunState(
+                                round=t,
+                                arrays=self._snapshot_arrays(executor,
+                                                             losses),
+                                history=history,
+                                extra={"sim_time": sim_time}))
+
+            with self.obs.span("fleet_unstack"):
+                executor.finalize()
             return RunResult(history, self.global_params)
         finally:
             self.obs.close()
@@ -1157,40 +1164,42 @@ class FedDDServer:
             with self.obs.span("chunk_dispatch", round=t):
                 trace = executor.run_chunk(t, k, losses)
             wall = (time.perf_counter() - t0) / k
-            tr_losses = np.asarray(trace.losses, float)
-            tr_dens = np.asarray(trace.densities, float)
-            tr_dnext = np.asarray(trace.next_dropout, np.float64)
-            tr_part = np.asarray(trace.participants, bool)
-            tr_oh = (None if trace.wire_overhead is None
-                     else np.asarray(trace.wire_overhead))
-            for j in range(k):
-                d_used = self.dropout.copy()
-                part = tr_part[j]
-                losses = tr_losses[j]
-                if cfg.scheme == "feddd":
-                    # the sequential driver clips the device rates in
-                    # float64 (solve_dropout_rates_with); replay that on
-                    # the traced rates so records match bit for bit
-                    self.dropout = np.clip(tr_dnext[j], 0.0, cfg.d_max)
-                uploaded, wire = account_uplink(
-                    tr_dens[j], part, self.tel.model_bytes,
-                    None if tr_oh is None else tr_oh[j], cfg.comm,
-                    obs=self.obs)
-                sim_time, round_t, _, t_all = self._finish_round(
-                    part, sim_time, None, d_used)
-                history.append(RoundRecord(
-                    round=t + j, sim_time=sim_time,
-                    sim_round_time=round_t, host_wall_time=wall,
-                    mean_loss=float(np.mean(losses)),
-                    dropout_rates=self.dropout.copy(),
-                    uploaded_fraction=uploaded / max(full_bytes, 1e-9),
-                    uploaded_bytes=uploaded, wire_bytes=wire,
-                    participants=int(np.sum(part)),
-                    survivors=int(np.sum(part))))
-                if self.obs.active:
-                    self.obs.round(
-                        history[-1], path="scanned", scheme=cfg.scheme,
-                        client_times=np.where(part, t_all, np.nan))
+            # the chunk's records, rebuilt on the host from its ScanTrace
+            with self.obs.span("round_records", round=t):
+                tr_losses = np.asarray(trace.losses, float)
+                tr_dens = np.asarray(trace.densities, float)
+                tr_dnext = np.asarray(trace.next_dropout, np.float64)
+                tr_part = np.asarray(trace.participants, bool)
+                tr_oh = (None if trace.wire_overhead is None
+                         else np.asarray(trace.wire_overhead))
+                for j in range(k):
+                    d_used = self.dropout.copy()
+                    part = tr_part[j]
+                    losses = tr_losses[j]
+                    if cfg.scheme == "feddd":
+                        # the sequential driver clips the device rates in
+                        # float64 (solve_dropout_rates_with); replay that
+                        # on the traced rates so records match bit for bit
+                        self.dropout = np.clip(tr_dnext[j], 0.0, cfg.d_max)
+                    uploaded, wire = account_uplink(
+                        tr_dens[j], part, self.tel.model_bytes,
+                        None if tr_oh is None else tr_oh[j], cfg.comm,
+                        obs=self.obs)
+                    sim_time, round_t, t_all = self._clock(part, sim_time,
+                                                           d_used)
+                    history.append(RoundRecord(
+                        round=t + j, sim_time=sim_time,
+                        sim_round_time=round_t, host_wall_time=wall,
+                        mean_loss=float(np.mean(losses)),
+                        dropout_rates=self.dropout.copy(),
+                        uploaded_fraction=uploaded / max(full_bytes, 1e-9),
+                        uploaded_bytes=uploaded, wire_bytes=wire,
+                        participants=int(np.sum(part)),
+                        survivors=int(np.sum(part))))
+                    if self.obs.active:
+                        self.obs.round(
+                            history[-1], path="scanned", scheme=cfg.scheme,
+                            client_times=np.where(part, t_all, np.nan))
             t += k
 
     def _record(self, t: int, t0: float, sim_time: float,
@@ -1209,10 +1218,10 @@ class FedDDServer:
             survivors=int(np.sum(active)),
             epsilon=eps_val, metrics=metrics)
 
-    def _finish_round(self, active: np.ndarray, sim_time: float, eval_fn,
-                      dropout_used: np.ndarray
-                      ) -> "tuple[float, float, Optional[Dict], np.ndarray]":
-        """Simulated wall clock (paper Eq. (12)) + optional eval.
+    def _clock(self, active: np.ndarray, sim_time: float,
+               dropout_used: np.ndarray
+               ) -> "tuple[float, float, np.ndarray]":
+        """Simulated wall clock (paper Eq. (12)).
 
         ``dropout_used`` is D_t — the rates this round's uploads actually
         used (NOT the freshly allocated D_{t+1}; the allocation for the
@@ -1223,9 +1232,9 @@ class FedDDServer:
         repro.comm.payload.analytic_wire_bytes) instead of the idealized
         ``U(1-D)``; the downlink broadcast stays idealized.
 
-        Also returns ``t_all`` — the per-client Eq. (12) round times the
-        max ran over; the recorder logs them (masked to active clients)
-        as the straggler timeline.
+        Returns ``(sim_time, round_time, t_all)``; ``t_all`` holds the
+        per-client Eq. (12) round times the max ran over; the recorder
+        logs them (masked to active clients) as the straggler timeline.
         """
         d_for_time = (dropout_used if self.cfg.scheme == "feddd"
                       else np.zeros(self.tel.num_clients))
@@ -1235,13 +1244,7 @@ class FedDDServer:
         t_all = baselines.round_times(self.tel, d_for_time,
                                       uplink_bytes=up)
         round_t = float(np.max(t_all[active]))
-        sim_time += round_t
-        if eval_fn:
-            with self.obs.span("eval"):
-                metrics = eval_fn(self.global_params)
-        else:
-            metrics = None
-        return sim_time, round_t, metrics, t_all
+        return sim_time + round_t, round_t, t_all
 
     # -- heterogeneous-model plumbing  (HeteroFL-style width slicing) --------
 

@@ -68,6 +68,7 @@ from repro.comm import quantize as wire_quant
 from repro.comm.payload import CommConfig, WireSpec, analytic_wire_bytes
 from repro.core import (aggregation, allocation, baselines, selection,
                         sparse_collective)
+from repro.obs import NULL_RECORDER
 
 
 class RoundOutputs(NamedTuple):
@@ -1150,9 +1151,32 @@ class GroupedRoundEngine:
                                    out.densities, out.wire_overhead)
 
 
+def train_clients(stacked, indices, local_train_fn, rk, part, losses,
+                  loss_dev: List, obs=NULL_RECORDER) -> List:
+    """Unstack ``stacked`` (rows = fleet clients ``indices``), train each
+    participant through ``local_train_fn`` and return the new per-client
+    pytrees in row order; each loss lands in ``loss_dev`` at its fleet
+    position.  Non-participants keep stale params and their stale loss.
+    The per-client trainer loop of both the grouped and the homogeneous
+    engine paths, under the ``group_unstack`` / ``client_train`` spans."""
+    with obs.span("group_unstack"):
+        per_client = unstack_pytree(stacked, len(indices))
+    new_list = []
+    for pos, i in enumerate(indices):
+        if part[i]:
+            with obs.span("client_train"):
+                p, l = local_train_fn(per_client[pos], i,
+                                      jax.random.fold_in(rk, i))
+        else:
+            p, l = per_client[pos], losses[i]
+        new_list.append(p)
+        loss_dev[i] = l
+    return new_list
+
+
 def train_grouped(groups, group_stacked, group_coverage, local_train_fn,
                   rk, part, losses, d_used, *, dense: bool,
-                  num_clients: int):
+                  num_clients: int, obs=NULL_RECORDER):
     """Per-client local training over grouped stacked state + GroupBatch
     assembly — the host-side half of a grouped round, shared by the
     protocol executor and the sim runner so the two stay in lockstep.
@@ -1161,26 +1185,22 @@ def train_grouped(groups, group_stacked, group_coverage, local_train_fn,
     where everyone trains); non-participants keep stale params and their
     stale loss.  Returns ``(loss_dev, batches)``: per-client device losses
     in fleet order and one complete :class:`GroupBatch` per group.
+    ``obs`` (a repro.obs recorder) times each group's unstack, each
+    client's training and each group's restack.
     """
     loss_dev: List = [None] * num_clients
     batches: List[GroupBatch] = []
     for grp, stacked, cov in zip(groups, group_stacked, group_coverage):
-        per_client = unstack_pytree(stacked, grp.size)
-        new_list = []
-        for pos, i in enumerate(grp.indices):
-            if part[i]:
-                p, l = local_train_fn(per_client[pos], i,
-                                      jax.random.fold_in(rk, i))
-            else:
-                p, l = per_client[pos], losses[i]
-            new_list.append(p)
-            loss_dev[i] = l
-        batches.append(GroupBatch(
-            indices=jnp.asarray(grp.indices, jnp.int32),
-            stacked_old=stacked,
-            stacked_new=stack_pytrees(new_list),
-            coverage=None if dense else cov,
-            dropout=jnp.asarray(d_used[list(grp.indices)], jnp.float32)))
+        new_list = train_clients(stacked, grp.indices, local_train_fn, rk,
+                                 part, losses, loss_dev, obs)
+        with obs.span("group_stack"):
+            batches.append(GroupBatch(
+                indices=jnp.asarray(grp.indices, jnp.int32),
+                stacked_old=stacked,
+                stacked_new=stack_pytrees(new_list),
+                coverage=None if dense else cov,
+                dropout=jnp.asarray(d_used[list(grp.indices)],
+                                    jnp.float32)))
     return loss_dev, batches
 
 
@@ -1218,13 +1238,14 @@ class GroupedFleetState:
         self._batches = None
 
     def train(self, local_train_fn, rk, part, losses, d_used,
-              *, dense: bool) -> List:
+              *, dense: bool, obs=NULL_RECORDER) -> List:
         """Run local training and stage this round's GroupBatches; returns
-        per-client device losses (fleet order)."""
+        per-client device losses (fleet order).  ``obs``: the recorder
+        :func:`train_grouped` spans with."""
         loss_dev, self._batches = train_grouped(
             self.groups, self.group_stacked, self.coverage, local_train_fn,
             rk, part, losses, d_used, dense=dense,
-            num_clients=self.num_clients)
+            num_clients=self.num_clients, obs=obs)
         return loss_dev
 
     def step(self, global_params, weights, rk, *, full_round: bool,

@@ -11,7 +11,11 @@ Entry points:
 * ``repro.obs.runlog`` — schema-versioned JSONL events; round events
   round-trip to bit-identical RoundRecords.
 * ``python -m repro.obs.report <run.jsonl>`` — phase/byte/failure
-  summaries, straggler timelines, ``--csv`` / ``--prom`` export.
+  summaries, compile counts, straggler timelines, ``--csv`` / ``--prom``
+  export.
+* :data:`PHASES` — the host-span vocabulary; ``repro.obs.recorder``'s
+  docstring says what each span covers and which driver emits it, and
+  how the compile counter catches recompiles mid-run.
 
 Import discipline: core/sim modules import ``repro.obs``; nothing in
 this package imports core/sim at module level (runlog pulls RoundRecord

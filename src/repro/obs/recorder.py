@@ -27,6 +27,54 @@ A live :class:`Recorder` composes three sinks:
 Everything the recorder consumes is already host-side (the round's one
 ``device_get`` / the chunk's ``ScanTrace`` pull): recording adds no
 device->host transfers.
+
+Span vocabulary (:data:`PHASES`; ``protocol`` is ``FedDDServer.run`` and
+its executors in core/protocol.py, ``sim`` the wave runner in
+sim/runner.py).  Spans nest: each JSONL ``span`` event names the span
+that enclosed it as ``parent`` (null at the top level).
+
+==================  ========================================  ===========
+name                what it covers                            emitted by
+==================  ========================================  ===========
+``fleet_stack``     building the executor: stacking client    protocol
+                    params, or grouping, coverage and
+                    per-group stacking for ragged fleets
+``allocate``        the Eq. (9)-(11) dropout-rate LP          both
+``local_train``     local training: the fused trainer's       both
+                    dispatch, or the per-client loop
+``group_unstack``   one group's (or the fleet's) stacked      protocol,
+                    params into per-client slices             sim grouped
+``client_train``    one client's ``local_train_fn`` call,     protocol,
+                    nested in ``local_train``                 sim grouped
+``group_stack``     restacking the trained clients (and       protocol,
+                    assembling a group's ``GroupBatch``)      sim grouped
+``encode``          the reference loop's mask building        protocol
+``aggregate``       the reference loop's Eq. (4)              protocol
+``client_update``   the reference loop's Eq. (5)/(6)          protocol
+``engine_step``     the fused server step's dispatch          both
+``host_transfer``   the round's (chunk's) one device_get      both
+``chunk_dispatch``  one scanned chunk of rounds               protocol
+``transport``       the simulated event timeline              sim
+``eval``            the caller's ``eval_fn``                  both
+``round_records``   host bookkeeping after a round (Eq. (12)  protocol
+                    clock, RoundRecord, run-log events,
+                    checkpoint test); one per scanned chunk
+``fleet_unstack``   ``finalize``: the stacked state back      protocol
+                    into ``server.clients``
+==================  ========================================  ===========
+
+Compile counter: while a live recorder is open it listens to
+``jax.monitoring`` and counts the programs JAX compiles into
+``feddd_compiles_total{kind=compile}`` (every backend compile, a load
+from the persistent cache included) and ``{kind=cache_load}`` (the loads
+among them), logging a ``compile`` event with the seconds taken and the
+round of the innermost open span that names one (null outside rounds:
+the fleet's stacking and unstacking, the key split between rounds).
+``python -m repro.obs.report`` prints the counts by round.  A fleet
+compiles in its first rounds (round 1, and round ``h``, the first full
+broadcast); a compile in any later round is a recompile mid-run (new
+shapes, new closed-over data, a changed static argument), and a second
+``run`` of a warm fleet in the same process should count none.
 """
 
 from __future__ import annotations
@@ -41,11 +89,20 @@ import numpy as np
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.runlog import SCHEMA_VERSION, JsonlWriter, round_event
 
-# Round-pipeline phase names (host spans + the named_scope annotations in
-# core/round_engine.py use the same vocabulary).
-PHASES = ("allocate", "local_train", "encode", "transport", "decode",
-          "aggregate", "eval", "engine_step", "host_transfer",
-          "chunk_dispatch", "client_update")
+# The host-span vocabulary (module docstring: what each covers and which
+# driver emits it).  The device programs' ``feddd_*`` named scopes in
+# core/round_engine.py are a separate, device-side vocabulary.
+PHASES = ("fleet_stack", "allocate", "local_train", "group_unstack",
+          "client_train", "group_stack", "encode", "aggregate",
+          "client_update", "engine_step", "host_transfer", "chunk_dispatch",
+          "transport", "eval", "round_records", "fleet_unstack")
+
+# jax.monitoring duration events -> ``feddd_compiles_total`` kinds (the
+# benchmark's bench/run.py CompileCounter counts the same two events)
+COMPILE_EVENTS = {
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -153,8 +210,14 @@ class Recorder:
         self._host_s = 0.0
         self._sim_s = 0.0
         self._closed = False
+        self._open: list = []            # names of the spans now open
+        # the round of the innermost open span that names one
+        self._round: Optional[int] = None
         self.event("run_start", schema=SCHEMA_VERSION, driver=driver,
                    **meta)
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
 
     # -- spans -----------------------------------------------------------
 
@@ -168,9 +231,17 @@ class Recorder:
         if self.cfg.trace:
             import jax
             ctx = jax.profiler.TraceAnnotation(name)
+        prev_round = self._round
+        if round is not None:
+            self._round = int(round)
         start = time.perf_counter()
-        with ctx:
-            yield
+        self._open.append(name)
+        try:
+            with ctx:
+                yield
+        finally:
+            self._open.pop()
+            self._round = prev_round
         self.span_done(name, start, round=round)
 
     def span_done(self, name: str, t_start: float,
@@ -179,14 +250,26 @@ class Recorder:
 
         For phases awkward to wrap in a ``with`` block (the sim runner's
         event-timeline section).  No profiler annotation — retroactive
-        spans cannot wrap device dispatches.
+        spans cannot wrap device dispatches.  Its ``parent`` is the span
+        open now, if any.
         """
         dur = time.perf_counter() - t_start
         self.registry.observe("feddd_span_seconds", dur, name=name)
-        ev = {"name": name, "t_start": t_start - self._t0, "dur_s": dur}
+        ev = {"name": name, "t_start": t_start - self._t0, "dur_s": dur,
+              "parent": self._open[-1] if self._open else None}
         if round is not None:
             ev["round"] = int(round)
         self.event("span", **ev)
+
+    def _on_duration(self, event: str, duration: float, **_) -> None:
+        """``jax.monitoring`` listener: count and log compiles (``round``:
+        that of the innermost open span naming one, else null)."""
+        kind = COMPILE_EVENTS.get(event)
+        if kind is None:
+            return
+        self.registry.inc("feddd_compiles_total", 1, kind=kind)
+        self.event("compile", kind=kind, round=self._round,
+                   seconds=float(duration))
 
     # -- events ----------------------------------------------------------
 
@@ -251,6 +334,8 @@ class Recorder:
         if self._closed:
             return
         self._closed = True
+        import jax.monitoring
+        jax.monitoring.unregister_event_duration_listener(self._on_duration)
         wall = time.perf_counter() - self._t0
         rps = self._rounds / wall if wall > 0 else 0.0
         self.registry.set("feddd_rounds_per_sec", rps)

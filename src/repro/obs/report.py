@@ -9,8 +9,12 @@ Renders, from the structured events alone (repro.obs.runlog):
 
 * run header — driver, scheme, fleet, wall/sim seconds, rounds/sec;
 * per-phase time breakdown — host span totals (calls, total s, mean ms,
-  share of spanned time) for the allocate → train → encode → transport →
-  aggregate → eval pipeline;
+  share of the top-level spanned time) over the ``repro.obs.PHASES``
+  vocabulary, nested spans (``client_train`` in ``local_train``)
+  indented under their parent;
+* compiles — the recorder's ``jax.monitoring`` counter: programs
+  compiled or loaded from the persistent cache, by the round they fell
+  in (one after the fleet's first rounds is a recompile mid-run);
 * byte economy — uploaded vs on-wire totals, wire overhead/savings,
   abandoned + quarantined bytes;
 * failure economy — skipped rounds, survivor stats, retries, incident
@@ -75,23 +79,65 @@ def _header_lines(events: List[Dict]) -> List[str]:
 
 
 def _phase_lines(events: List[Dict]) -> List[str]:
+    """Host span totals.  Spans nest (each event names its ``parent``):
+    shares are of the top-level spans' time, and each child is listed
+    indented under its parent, so nested time is not counted twice."""
     spans = [e for e in events if e.get("event") == "span"]
     lines = _section("Phase breakdown (host spans)")
     if not spans:
         lines.append("  no span events (log written without spans?)")
         return lines
-    agg: Dict[str, List[float]] = defaultdict(lambda: [0, 0.0])
+    agg: Dict[tuple, List[float]] = defaultdict(lambda: [0, 0.0])
     for e in spans:
-        a = agg[e["name"]]
+        a = agg[(e.get("parent"), e["name"])]
         a[0] += 1
         a[1] += float(e["dur_s"])
-    total = sum(a[1] for a in agg.values()) or 1.0
-    lines.append(f"  {'phase':<16}{'calls':>7}{'total_s':>10}"
+    total = sum(a[1] for (parent, _), a in agg.items()
+                if parent is None) or 1.0
+    lines.append(f"  {'phase':<20}{'calls':>7}{'total_s':>10}"
                  f"{'mean_ms':>10}{'share':>8}")
-    for name, (calls, tot) in sorted(agg.items(), key=lambda kv: -kv[1][1]):
-        lines.append(f"  {name:<16}{calls:>7}{tot:>10.4f}"
-                     f"{1e3 * tot / calls:>10.3f}"
-                     f"{100.0 * tot / total:>7.1f}%")
+
+    def rows(parent, depth, seen):
+        kids = sorted(((name, a) for (par, name), a in agg.items()
+                       if par == parent and name not in seen),
+                      key=lambda kv: -kv[1][1])
+        for name, (calls, tot) in kids:
+            label = "  " * depth + name
+            lines.append(f"  {label:<20}{calls:>7}{tot:>10.4f}"
+                         f"{1e3 * tot / calls:>10.3f}"
+                         f"{100.0 * tot / total:>7.1f}%")
+            rows(name, depth + 1, seen | {name})
+
+    rows(None, 0, frozenset())
+    return lines
+
+
+def _compile_lines(events: List[Dict]) -> List[str]:
+    """The recorder's compile counter (``compile`` events): programs JAX
+    compiled or loaded from its persistent cache during the run, and the
+    rounds they fell in.  A fleet compiles in its first rounds (round 1,
+    and the first full round ``h``); a compile in a later round is a
+    recompile mid-run."""
+    comp = [e for e in events if e.get("event") == "compile"]
+    lines = _section("Compiles (jax.monitoring)")
+    if not comp:
+        lines.append("  none recorded")
+        return lines
+    by_kind: Dict[str, List[float]] = defaultdict(lambda: [0, 0.0])
+    by_round: Dict[str, int] = defaultdict(int)
+    for e in comp:
+        a = by_kind[e.get("kind", "unknown")]
+        a[0] += 1
+        a[1] += float(e.get("seconds", 0.0))
+        if e.get("kind") == "compile":
+            r = e.get("round")
+            by_round["outside rounds" if r is None else f"r{r}"] += 1
+    lines.append("  " + "  ".join(f"{k}={int(n)} ({s:.3f} s)"
+                                  for k, (n, s) in sorted(by_kind.items())))
+    order = sorted(by_round, key=lambda k: (k[0] != "r",
+                                            int(k[1:]) if k[0] == "r" else 0))
+    lines.append("  compiles by round: "
+                 + "  ".join(f"{k}={by_round[k]}" for k in order))
     return lines
 
 
@@ -273,6 +319,7 @@ def render(events: List[Dict], top: int = 5) -> str:
     lines: List[str] = []
     lines += _header_lines(events)
     lines += _phase_lines(events)
+    lines += _compile_lines(events)
     lines += _byte_lines(rounds, events)
     lines += _failure_lines(events, rounds)
     lines += _outage_lines(events)

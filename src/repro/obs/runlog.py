@@ -13,8 +13,12 @@ Event kinds (the ``event`` field):
   ``RunResult`` history, bit for bit — Python's ``json`` emits float64
   ``repr`` which parses back to the identical double, and every array
   field is written as a list of native floats.
-* ``span`` — one per host-side span (``name``, chunk-relative ``t_start``
-  and ``dur_s``, optional ``round``).
+* ``span`` — one per host-side span (``name``, run-relative ``t_start``
+  and ``dur_s``, ``parent``: the enclosing span's name or null, optional
+  ``round``).
+* ``compile`` — one per program JAX compiled or loaded from its
+  persistent cache while the recorder was open (``kind`` compile |
+  cache_load, ``seconds``, ``round`` or null).
 * ``fault`` — one per fault incident (crash / retry / abort / corrupt /
   quarantine / quorum_skip), from ``repro.sim.faults.incident_events``.
 * ``run_end`` — totals (rounds, host seconds, rounds/sec).

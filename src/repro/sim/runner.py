@@ -267,7 +267,8 @@ class _GroupedWaveFleet:
 
     def train(self, local_train_fn, rk, part, losses, d_used) -> List:
         return self.state.train(local_train_fn, rk, part, losses, d_used,
-                                dense=self.runner.cfg.scheme != "feddd")
+                                dense=self.runner.cfg.scheme != "feddd",
+                                obs=self.runner.obs)
 
     def step(self, d_used, weights, rk, *, full_round, dense,
              delivered=None, overrides=None):

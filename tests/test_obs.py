@@ -264,6 +264,78 @@ def test_obs_enabled_triggers_no_recompile(tmp_path):
     assert _round_step._cache_size() == warm
 
 
+# --- span vocabulary: the driver's and executors' host moments ---------------
+
+@pytest.mark.parametrize("path", ["engine", "grouped", "scanned"])
+def test_run_spans_cover_stack_records_and_unstack(path, tmp_path):
+    """Every host moment of ``FedDDServer.run`` that can hold the device
+    idle is a span: the executor's build and finalize once per run, the
+    round bookkeeping once per round (per chunk when scanned), and the
+    per-client loop's unstack / client / restack inside local_train."""
+    from repro.obs import PHASES
+    _run_path(path, True, tmp_path)
+    spans = [e for e in read_events(str(tmp_path / f"{path}.jsonl"))
+             if e["event"] == "span"]
+    names = [e["name"] for e in spans]
+    rounds, clients = 4, (8 if path == "scanned" else 6)
+    assert names.count("fleet_stack") == 1
+    assert names.count("fleet_unstack") == 1
+    assert names[0] == "fleet_stack" and names[-1] == "fleet_unstack"
+    assert names.count("round_records") == (2 if path == "scanned"
+                                            else rounds)
+    groups = {"engine": 1, "grouped": 3, "scanned": 0}[path]
+    per_client = groups > 0
+    assert names.count("client_train") == rounds * clients * per_client
+    assert names.count("group_unstack") == rounds * groups
+    assert names.count("group_stack") == rounds * groups
+    parents = {e["name"]: e["parent"] for e in spans}
+    for nested in ("client_train", "group_unstack", "group_stack"):
+        if per_client:
+            assert parents[nested] == "local_train"
+    for top in ("fleet_stack", "fleet_unstack", "round_records"):
+        assert parents[top] is None
+    assert set(names) <= set(PHASES)
+
+
+def test_fleet_spans_close_with_a_failing_executor(tmp_path):
+    """The executor is built inside the recorder's lifetime: a
+    configuration error there still closes the run log."""
+    gp, clients = _ragged_fleet(6)
+    log = tmp_path / "bad.jsonl"
+    cfg = ProtocolConfig(scheme="feddd", rounds=1, mesh=1,
+                         mesh_collective="sparse",
+                         obs=ObsConfig(jsonl_path=str(log)))
+    srv = FedDDServer(gp, cfg, _tel(6, [_nbytes(p) for p in clients]),
+                      client_params=clients)
+    with pytest.raises(ValueError):
+        srv.run(_ltf)
+    assert srv.obs is NULL_RECORDER
+    assert read_events(str(log))[-1]["event"] == "run_end"
+
+
+def test_compile_counter_counts_then_reads_zero_warm(tmp_path):
+    """The recorder counts the programs JAX compiles while it is open: a
+    fleet size no other test uses compiles, the same run again does not
+    (and the run log carries one ``compile`` event per count)."""
+    params = _params(jax.random.PRNGKey(0))
+    tel = _tel(7, _nbytes(params))
+    counts = []
+    for k in range(2):
+        reg = MetricsRegistry()
+        log = tmp_path / f"compile{k}.jsonl"
+        run_scheme("feddd", params, tel, _ltf, None, rounds=2, a_server=0.6,
+                   h=3, seed=0, obs=ObsConfig(registry=reg,
+                                              jsonl_path=str(log)))
+        n = reg.value("feddd_compiles_total", kind="compile") or 0.0
+        events = [e for e in read_events(str(log))
+                  if e["event"] == "compile" and e["kind"] == "compile"]
+        assert len(events) == n
+        assert all(e["seconds"] >= 0.0 for e in events)
+        counts.append(n)
+    assert counts[0] >= 1
+    assert counts[1] == 0
+
+
 # --- JSONL run log -----------------------------------------------------------
 
 def test_jsonl_roundtrips_history_exactly(tmp_path):
@@ -385,6 +457,26 @@ def test_report_cli_renders_and_exports(tmp_path, capsys):
     ptext = prom.read_text()
     assert "feddd_rounds_total" in ptext
     assert "feddd_sim_time_seconds" in ptext
+
+
+def test_report_nests_child_spans_under_their_parent(tmp_path, capsys):
+    """Child spans (client_train in local_train) are listed indented
+    under their parent and left out of the shares, which sum to 100 %."""
+    _run_path("grouped", True, tmp_path)
+    assert obs_report.main([str(tmp_path / "grouped.jsonl")]) == 0
+    out = capsys.readouterr().out
+    block = out.split("Phase breakdown")[1].split("\n\n")[0]
+    rows = [l for l in block.splitlines()[3:] if l.strip()]
+    top = [l for l in rows if not l.startswith("    ")]
+    child = [l for l in rows if l.startswith("    ")]
+    assert {l.split()[0] for l in child} == {"client_train",
+                                             "group_unstack", "group_stack"}
+    # every child row follows its parent's row
+    first_child = rows.index(child[0])
+    assert rows[first_child - 1].split()[0] == "local_train"
+    shares = [float(l.split()[-1].rstrip("%")) for l in top]
+    assert sum(shares) == pytest.approx(100.0, abs=0.1 * len(top))
+    assert "Compiles (jax.monitoring)" in out
 
 
 def test_report_cli_rejects_non_runlog(tmp_path):
