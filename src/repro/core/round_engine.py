@@ -169,15 +169,34 @@ class ScanTrace(NamedTuple):
                                    # per-round renderings agree exactly
 
 
-def stack_pytrees(trees: Sequence) -> object:
-    """[pytree] x N (identical structure/shapes) -> pytree of (N, *leaf)."""
+@jax.jit
+def _stack_compiled(trees: List) -> object:
     return jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *trees)
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _unstack_compiled(stacked, n: int) -> Tuple:
+    leaves, treedef = jax.tree_util.tree_flatten(stacked)
+    rows = [[lax.index_in_dim(l, i, keepdims=False) for i in range(n)]
+            for l in leaves]
+    return tuple(treedef.unflatten([r[i] for r in rows]) for i in range(n))
+
+
+def stack_pytrees(trees: Sequence) -> object:
+    """[pytree] x N (identical structure/shapes) -> pytree of (N, *leaf).
+
+    ONE compiled dispatch per call (jit keys on the tree, the leaf avals
+    and N): eager stacking costs an ``expand_dims`` per client and leaf
+    plus a concatenate per leaf, each a host-side dispatch."""
+    return _stack_compiled(list(trees))
+
+
 def unstack_pytree(stacked, n: int) -> List:
-    """Inverse of :func:`stack_pytrees` (lazy device slices, no host sync)."""
-    return [jax.tree_util.tree_map(lambda l: l[i], stacked)
-            for i in range(n)]
+    """Inverse of :func:`stack_pytrees`: the first ``n`` rows of every
+    leaf as ``n`` per-client pytrees, in ONE compiled dispatch (no host
+    sync; ``n`` is static, so each (tree, avals, n) compiles once).  The
+    input is not donated: callers keep the stacked state."""
+    return list(_unstack_compiled(stacked, n))
 
 
 def _adopt_global(new_global, stacked):

@@ -651,3 +651,116 @@ def test_make_federated_allreduce_forwards_k_local():
     from repro.core import sparse_collective
     hints = typing.get_type_hints(sparse_collective.sparse_allgather_mean)
     assert "k_local" in hints
+
+
+# --- stack / unstack: one compiled dispatch, bit-equal to the eager ops ----
+
+def _eager_stack(trees):
+    return jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *trees)
+
+
+def _eager_unstack(stacked, n):
+    return [jax.tree_util.tree_map(lambda l: l[i], stacked)
+            for i in range(n)]
+
+
+def _assert_leaves_identical(a, b):
+    """Same tree, and per leaf the same shape, dtype, weak type and bits."""
+    assert jax.tree_util.tree_structure(a) == jax.tree_util.tree_structure(b)
+    for x, y in zip(jax.tree_util.tree_leaves(a),
+                    jax.tree_util.tree_leaves(b)):
+        assert (x.shape, x.dtype, x.weak_type) == \
+            (y.shape, y.dtype, y.weak_type)
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _mixed_dtype_client(key, i):
+    k1, k2 = jax.random.split(jax.random.fold_in(key, i))
+    return {"enc": {"w": jax.random.normal(k1, (3, 4)),
+                    "scale": jax.random.normal(k2, (4,)).astype(
+                        jnp.bfloat16)},
+            "steps": jnp.arange(5, dtype=jnp.int32) + i}
+
+
+def _scalar_leaf_client(key, i):
+    return {"w": jax.random.normal(jax.random.fold_in(key, i), (2, 3)),
+            "loss": jnp.float32(0.5 * i), "step": jnp.int32(i)}
+
+
+def _toy_width(spec):
+    """A Table 3 VGG layer list with every width divided by 8."""
+    def cut(c):
+        return c if c in (3, 10) else c // 8
+    return [l if l[0] == "pool" else (l[0], cut(l[1]), cut(l[2])) + l[3:]
+            for l in spec]
+
+
+def _hetero_a_toy_fleets(key, per_width):
+    """One fleet per Table 3 (hetero-a) width, at toy size (leaf shapes
+    from ``init_cnn_spec``, values drawn on the host: no compile per
+    shape)."""
+    from repro.fl.models import HETERO_A_SPECS, init_cnn_spec
+    rng = np.random.default_rng(int(key[-1]))
+    fleets = []
+    for spec in HETERO_A_SPECS:
+        shapes = jax.eval_shape(
+            lambda k, s=_toy_width(spec): init_cnn_spec(k, s), key)
+        fleets.append([jax.tree_util.tree_map(
+            lambda a: jnp.asarray(rng.standard_normal(a.shape), a.dtype),
+            shapes) for _ in range(per_width)])
+    return fleets
+
+
+@pytest.mark.parametrize("fleet,n", [
+    ("mixed_dtypes", 1), ("mixed_dtypes", 13),
+    ("scalar_leaves", 1), ("scalar_leaves", 13),
+    ("vgg_hetero_a_toy", 3)])
+def test_stack_unstack_bit_equal_to_eager_and_round_trip(fleet, n):
+    """The compiled stack / unstack give the eager ``jnp.stack`` / ``l[i]``
+    results bit for bit (values, shapes, dtypes), invert each other, and
+    ``unstack_pytree(x, 1)[0]`` of an n-row stack is its first client."""
+    key = jax.random.PRNGKey(4)
+    if fleet == "vgg_hetero_a_toy":
+        fleets = _hetero_a_toy_fleets(key, n)
+    else:
+        make = {"mixed_dtypes": _mixed_dtype_client,
+                "scalar_leaves": _scalar_leaf_client}[fleet]
+        fleets = [[make(key, i) for i in range(n)]]
+    for trees in fleets:
+        stacked = stack_pytrees(trees)
+        _assert_leaves_identical(stacked, _eager_stack(trees))
+        clients = unstack_pytree(stacked, n)
+        assert len(clients) == n
+        for got, want, orig in zip(clients, _eager_unstack(stacked, n),
+                                   trees):
+            _assert_leaves_identical(got, want)
+            _assert_leaves_identical(got, orig)
+        _assert_leaves_identical(stack_pytrees(clients), stacked)
+        _assert_leaves_identical(unstack_pytree(stacked, 1)[0], trees[0])
+
+
+def test_stack_unstack_second_fleet_of_same_shapes_compiles_nothing():
+    """jit keys the two programs on tree, leaf avals and n alone: a second
+    fleet of the same shapes (new values) compiles and loads nothing —
+    what keeps a benchmark window free of compiles."""
+    key = jax.random.PRNGKey(6)
+    first = [_mixed_dtype_client(key, i) for i in range(7)]
+    second = [_mixed_dtype_client(jax.random.fold_in(key, 1), i)
+              for i in range(7)]
+    unstack_pytree(stack_pytrees(first), 7)
+    jax.block_until_ready(second)
+    events = []
+
+    def listen(name, *_a, **_k):
+        if name in ("/jax/core/compile/backend_compile_duration",
+                    "/jax/compilation_cache/cache_retrieval_time_sec"):
+            events.append(name)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        clients = unstack_pytree(stack_pytrees(second), 7)
+        jax.block_until_ready(clients)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert events == []
+    _assert_leaves_identical(clients[6], second[6])
