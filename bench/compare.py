@@ -12,7 +12,7 @@ readings each limit was set from are in PERF.md).
 from __future__ import annotations
 
 import math
-from typing import Dict, List, Sequence
+from typing import Any, Dict, List, Sequence
 
 import numpy as np
 
@@ -24,20 +24,28 @@ import jax.numpy as jnp
 STILL_LEAF = 1e-3
 
 
+def leaves(tree) -> Dict[str, Any]:
+    """Leaf path -> leaf of a nested pytree, the path's keys joined by
+    dots (``fc0.w``, ``stack.super.p0.w``)."""
+    return {jax.tree_util.keystr(path, simple=True, separator="."): leaf
+            for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
 def change_norms(params, origin) -> Dict[str, float]:
     """Leaf path -> L2 norm of ``params - origin`` (float32 sums)."""
-    return {f"{nm}.{k}": float(jnp.sqrt(jnp.sum(jnp.square(
-        params[nm][k].astype(jnp.float32)
-        - origin[nm][k].astype(jnp.float32)))))
-        for nm in params for k in params[nm]}
+    o = leaves(origin)
+    return {k: float(jnp.sqrt(jnp.sum(jnp.square(
+        a.astype(jnp.float32) - o[k].astype(jnp.float32)))))
+        for k, a in leaves(params).items()}
 
 
 @jax.jit
 def _sq_change(p, origin):
-    return {nm: {k: jnp.sum(jnp.square(
-        a.astype(jnp.float32) - origin[nm][k][
-            tuple(slice(0, s) for s in a.shape)].astype(jnp.float32)))
-        for k, a in p[nm].items()} for nm in p}
+    return jax.tree_util.tree_map(
+        lambda a, o: jnp.sum(jnp.square(
+            a.astype(jnp.float32)
+            - o[tuple(slice(0, s) for s in a.shape)].astype(jnp.float32))),
+        p, origin)
 
 
 def clients_change_norms(client_params: Sequence, origin) -> Dict[str, float]:
@@ -45,9 +53,8 @@ def clients_change_norms(client_params: Sequence, origin) -> Dict[str, float]:
     the initial global (sliced to the client's widths)."""
     total: Dict[str, float] = {}
     for p in client_params:
-        for nm, leaves in jax.device_get(_sq_change(p, origin)).items():
-            for k, v in leaves.items():
-                total[f"{nm}.{k}"] = total.get(f"{nm}.{k}", 0.0) + float(v)
+        for k, v in leaves(jax.device_get(_sq_change(p, origin))).items():
+            total[k] = total.get(k, 0.0) + float(v)
     return {k: math.sqrt(v) for k, v in total.items()}
 
 

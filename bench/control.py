@@ -18,7 +18,12 @@ Modes (each prints, per seed, every number ``bench/run.py`` compares):
   fleet left out, the mean taken over the rest;
 * ``altered_rates`` -- the program's dropout-rate LP returning client 0's
   rate moved by 0.2 (down from above 0.4, else up): an answer altered
-  where it is produced.
+  where it is produced;
+* ``no_exchange`` -- on a client-sharded mesh, the Eq. (4) partials not
+  summed across chips: each chip's mean over its own clients alone.
+
+A fault covers every engine a cell can run: the stacked, grouped and
+client-sharded Eq. (4) steps alike.
 
 A fault is planted by replacing the program's function for the whole
 process, before anything is traced.
@@ -27,6 +32,7 @@ process, before anything is traced.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import gc
 import importlib
 import json
@@ -38,7 +44,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from bench import compare, run  # noqa: E402
 
-MODES = ("control", "program", "frozen", "half_clients", "altered_rates")
+MODES = ("control", "program", "frozen", "half_clients", "altered_rates",
+         "no_exchange")
 
 
 def _alter(rate):
@@ -51,13 +58,21 @@ def plant(mode: str, set_attr=setattr) -> None:
     """Replace the program's function that ``mode`` breaks, through
     ``set_attr(module, name, value)`` (a test passes its monkeypatch's)."""
     import jax.numpy as jnp
-    from repro.core import aggregation, allocation, protocol
+    from repro.core import aggregation, allocation, protocol, round_engine
 
     if mode == "frozen":
         set_attr(aggregation, "aggregate_sparse_stacked",
                  lambda params, masks, w, *, prev_global, **k: prev_global)
         set_attr(aggregation, "aggregate_sparse_grouped",
                  lambda *a, prev_global, **k: prev_global)
+        set_attr(round_engine, "_leaf_sharded_reduce",
+                 lambda num, den, gprev, dtype, **k:
+                 (gprev.astype(dtype), jnp.float32(0.0)))
+    elif mode == "no_exchange":
+        set_attr(round_engine, "_leaf_sharded_reduce",
+                 lambda num, den, gprev, dtype, **k:
+                 (aggregation.finish_masked_mean(num, den, gprev, dtype),
+                  jnp.float32(0.0)))
     elif mode == "half_clients":
         def halve(fn, pos):
             def wrapped(*a, **k):
@@ -70,6 +85,8 @@ def plant(mode: str, set_attr=setattr) -> None:
                  halve(aggregation.aggregate_sparse_stacked, 2))
         set_attr(aggregation, "aggregate_sparse_grouped",
                  halve(aggregation.aggregate_sparse_grouped, 3))
+        set_attr(round_engine.ShardedRoundEngine, "step",   # self first
+                 halve(round_engine.ShardedRoundEngine.step, 5))
     elif mode == "altered_rates":
         jax_lp = allocation.solve_dropout_rates_jax
 
@@ -87,9 +104,29 @@ def plant(mode: str, set_attr=setattr) -> None:
         set_attr(protocol, "solve_dropout_rates_with", lp_np)
 
 
+@contextlib.contextmanager
+def planted(mode: str):
+    """``mode``'s fault planted inside the block, with no compiled program
+    kept from before it or into what follows."""
+    import jax
+    undo = []
+
+    def set_attr(obj, name, value):
+        undo.append((obj, name, getattr(obj, name)))
+        setattr(obj, name, value)
+    plant(mode, set_attr)
+    jax.clear_caches()
+    try:
+        yield
+    finally:
+        for obj, name, value in reversed(undo):
+            setattr(obj, name, value)
+        jax.clear_caches()
+
+
 def readings(cell: dict, seed: int, mode: str) -> dict:
     conf, traffic = cell["config_data"], cell["traffic_data"]
-    setup = run.build_setup(conf, traffic, seed)
+    setup = run.build_setup(cell, seed)
     calls = run.check_calls(conf)
     reference = importlib.import_module(
         f"bench.references.{conf['reference']}")

@@ -1,12 +1,13 @@
 #!/usr/bin/env python3
 """One run of one benchmark cell of the FedDD round engines on the chip.
 
-    python3 bench/run.py --workload vgg_full_128.scanned --seed 7 \\
-        --seconds 10 --trace 0
+    python3 bench/run.py --workload <cell> --seed 7 --seconds 10 --trace 0
 
 Everything is found by name: the cell in ``BENCHMARK.json``, its
-configuration (``bench/configs/<config>.json``), its traffic mix
-(``bench/traffic/<traffic>.json``), its correctness limits
+configuration (``bench/configs/<config>.json``), the configuration's
+client model family (``bench/families/<family>.py``), its traffic mix
+(``bench/traffic/<traffic>.json``, which may place the fleet on a mesh
+over the cell's chips), its correctness limits
 (``bench/limits/<cell>.json``), its plain reference
 (``bench/references/<reference>.py``) and each per-layer metric's reader
 (``bench/metrics/<metric>.py``).
@@ -44,7 +45,6 @@ import importlib  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
-import types  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 ROOT = Path(__file__).resolve().parent.parent
@@ -59,7 +59,8 @@ import numpy as np  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from bench import compare, counts, fleet, trace as trace_mod  # noqa: E402
+from bench import (compare, counts, families, fleet,  # noqa: E402
+                   trace as trace_mod)
 
 
 # --------------------------------------------------------------- the cell
@@ -118,29 +119,47 @@ def check_calls(conf: dict) -> list:
 
 # ---------------------------------------------------------------- the fleet
 
-def build_setup(conf: dict, traffic: dict, seed: int) -> dict:
-    """Sizes, data, telemetry and layer lists from the configuration and
-    the seed: what the program is handed and the reference rebuilds."""
-    global_layers = fleet.vgg_layers(conf["global"]["conv"],
-                                     conf["global"]["fc"])
-    client_layers = [fleet.vgg_layers(g["conv"], g["fc"])
-                     for g in conf["fleet"] for _ in range(g["clients"])]
-    n, s = len(client_layers), conf["samples_per_client"]
+def placement(cell: dict) -> dict:
+    """Where the fleet runs: with ``"mesh": "clients"`` in the traffic mix
+    the client axis is sharded over the cell's chips (``"collective"``
+    names the cross-chip Eq. (4) reduction); without it, one device."""
+    traffic = cell["traffic_data"]
+    mesh = traffic.get("mesh")
+    if mesh is None:
+        return {}
+    if mesh != "clients":
+        raise ValueError(f"traffic {cell['traffic']!r}: mesh {mesh!r}; "
+                         "the one mesh is 'clients'")
+    return {"mesh": cell["chips"], "mesh_collective": traffic["collective"]}
+
+
+def build_setup(cell: dict, seed: int) -> dict:
+    """Sizes, data, telemetry and model descriptions from the cell's
+    configuration and the seed: what the program is handed and the
+    reference rebuilds."""
+    conf, traffic = cell["config_data"], cell["traffic_data"]
+    if "family" not in conf:
+        raise ValueError(f"configuration {conf['name']!r} names no family "
+                         "(bench/families/<family>.py)")
+    fam = families.load(conf["family"])
+    global_model, client_models = fam.models(conf)
+    n, s = len(client_models), conf["samples_per_client"]
     # one data set per configuration: client data a jitted trainer closes
     # over is compiled into the round program, so data drawn anew per seed
     # would compile anew per seed
-    xs, ys, xte, yte = fleet.client_shards(conf["data_seed"], n, s,
-                                           traffic["eval_samples"])
-    tel = fleet.telemetry(seed, [fleet.param_bytes(l) for l in client_layers],
+    xs, ys, xte, yte = fam.data(conf, n, traffic["eval_samples"])
+    tel = fleet.telemetry(seed, [fam.param_bytes(m) for m in client_models],
                           [s] * n, conf["local_epochs"])
-    return {"seed": seed, "global_layers": global_layers,
-            "client_layers": client_layers, "xs": xs, "ys": ys,
-            "xte": xte, "yte": yte, "telemetry": tel, "lr": conf["lr"],
-            "batch": conf["batch_size"], "epochs": conf["local_epochs"],
-            "a_server": conf["a_server"], "d_max": conf["d_max"],
-            "delta": conf["delta"], "h": conf["h"],
+    where = placement(cell)
+    return {"seed": seed, "family": conf["family"],
+            "global_model": global_model, "client_models": client_models,
+            "xs": xs, "ys": ys, "xte": xte, "yte": yte, "telemetry": tel,
+            "lr": conf["lr"], "batch": conf["batch_size"],
+            "epochs": conf["local_epochs"], "a_server": conf["a_server"],
+            "d_max": conf["d_max"], "delta": conf["delta"], "h": conf["h"],
             "order": "stored" if traffic["train"] == "fused" else "permuted",
-            "eval": traffic["eval_samples"] > 0}
+            "eval": traffic["eval_samples"] > 0, "placement": where,
+            "shards": cell["chips"] if where else 1}
 
 
 class Program:
@@ -153,49 +172,42 @@ class Program:
         from repro.core import FedDDServer, ProtocolConfig
         from repro.core.allocation import ClientTelemetry
         from repro.core.round_engine import make_batched_train_fn
-        from repro.fl.models import (apply_spec, make_eval_fn,
-                                     make_local_train_fn)
+        fam = families.load(setup["family"])
         kinds = []
-        for l in setup["client_layers"]:
-            if l not in kinds:
-                kinds.append(l)
-        self.g0, subs = fleet.make_weights(setup["seed"],
-                                           setup["global_layers"], kinds)
+        for m in setup["client_models"]:
+            if m not in kinds:
+                kinds.append(m)
+        self.g0, subs = fam.make_weights(setup["seed"],
+                                         setup["global_model"], kinds)
         # the program may donate what it is handed: keep the origin apart
         self.origin = jax.tree_util.tree_map(jnp.copy, self.g0)
+        where = setup["placement"]
         self.cfg = ProtocolConfig(
             scheme="feddd", a_server=setup["a_server"],
             d_max=setup["d_max"], delta=setup["delta"], h=setup["h"],
             seed=fleet.seed32(setup["seed"]),
             allocator=traffic["allocator"],
-            rounds_per_dispatch=traffic["rounds_per_dispatch"])
+            rounds_per_dispatch=traffic["rounds_per_dispatch"], **where)
         tel = ClientTelemetry(**setup["telemetry"])
-        n = len(setup["client_layers"])
-        self.eval_fn = None
-        if setup["eval"]:
-            self.eval_fn = make_eval_fn(
-                setup["global_layers"],
-                types.SimpleNamespace(x=setup["xte"], y=setup["yte"]),
-                batch_size=512)
+        self.eval_fn = fam.eval_fn(setup) if setup["eval"] else None
         if traffic["train"] == "fused":
-            step = fleet.make_client_step(
-                lambda p, x: apply_spec(p, setup["global_layers"], x),
-                setup["batch"], setup["lr"], setup["epochs"])
-            xs, ys = jnp.asarray(setup["xs"]), jnp.asarray(setup["ys"])
-            self.train = jax.jit(make_batched_train_fn(step, (xs, ys)))
+            step, data = fam.client_step(setup)
+            if where:
+                # the fleet's data lives on the client shards like its
+                # parameters, on the mesh the engine builds
+                from repro.launch.mesh import make_client_mesh
+                rows = jax.sharding.NamedSharding(
+                    make_client_mesh(where["mesh"]),
+                    jax.sharding.PartitionSpec("clients"))
+                data = tuple(jax.device_put(a, rows) for a in data)
+            else:
+                data = tuple(jnp.asarray(a) for a in data)
+            self.train = jax.jit(make_batched_train_fn(step, data))
             self.server = FedDDServer(self.g0, self.cfg, tel)
             self.local = None
         else:
-            s = setup["xs"].shape[1]
-            ds = types.SimpleNamespace(
-                x=setup["xs"].reshape(n * s, *setup["xs"].shape[2:]),
-                y=setup["ys"].reshape(n * s))
-            parts = np.arange(n * s).reshape(n, s)
-            fns = [make_local_train_fn(l, ds, parts, lr=setup["lr"],
-                                       batch_size=setup["batch"],
-                                       local_epochs=setup["epochs"])
-                   for l in kinds]
-            which = [kinds.index(l) for l in setup["client_layers"]]
+            fns = [fam.local_train_fn(setup, m) for m in kinds]
+            which = [kinds.index(m) for m in setup["client_models"]]
             self.local = lambda p, i, rng: fns[which[i]](p, i, rng)
             self.train = None
             self.server = FedDDServer(
@@ -212,10 +224,9 @@ class Program:
 
     def first_update(self) -> dict:
         """Leaf -> the global model's change from the initial weights."""
-        g = jax.device_get(self.server.global_params)
-        o = jax.device_get(self.origin)
-        return {f"{nm}.{k}": np.asarray(g[nm][k], np.float32) - o[nm][k]
-                for nm in g for k in g[nm]}
+        g = compare.leaves(jax.device_get(self.server.global_params))
+        o = compare.leaves(jax.device_get(self.origin))
+        return {k: np.asarray(a, np.float32) - o[k] for k, a in g.items()}
 
     def readings(self, first_update: dict, history: list) -> dict:
         """What the comparison takes from the checked calls: the first
@@ -276,7 +287,7 @@ def run_cell(cell: dict, seed: int, seconds: float, traced: bool,
     chips = cell["chips"]
     clock = {"start": time.perf_counter() - t_start}
     scopes = trace_mod.ScopeMap() if traced else None
-    setup = build_setup(conf, traffic, seed)
+    setup = build_setup(cell, seed)
     prog = Program(setup, traffic)
     clock["fleet"] = time.perf_counter() - t_start
     # the checked calls compile every program the window runs; one more
@@ -328,6 +339,7 @@ def run_cell(cell: dict, seed: int, seconds: float, traced: bool,
         red = trace_mod.reduce(trace_mod.load(tdir, scopes.modules),
                                list(range(chips)))
         window["trace_read_s"] = time.perf_counter() - t0
+        window["collective_ops"] = red["collectives"]
         shutil.rmtree(tdir, ignore_errors=True)
         ctx = {"trace": red, "rounds": rounds, "chips": chips,
                "setup": setup, "traffic": traffic,

@@ -74,6 +74,25 @@ def test_cell_loads_by_name(cell):
         <= set(c["limits"]) <= numbers
 
 
+@pytest.mark.parametrize("mix", sorted(
+    p.stem for p in (ROOT / "bench" / "traffic").glob("*.json")))
+def test_traffic_mix_builds_a_protocol_config(mix):
+    """Every mix, those no cell uses yet among them, gives a
+    ``ProtocolConfig`` the program accepts, placed on four chips where it
+    names a mesh."""
+    from repro.core import ProtocolConfig
+    traffic = json.loads(
+        (ROOT / "bench" / "traffic" / f"{mix}.json").read_text())
+    assert traffic["train"] in ("fused", "per_client")
+    assert traffic["eval_samples"] >= 0
+    where = run.placement({"traffic": mix, "chips": 4,
+                           "traffic_data": traffic})
+    assert bool(where) == ("mesh" in traffic)
+    ProtocolConfig(scheme="feddd", allocator=traffic["allocator"],
+                   rounds_per_dispatch=traffic["rounds_per_dispatch"],
+                   **where)
+
+
 @pytest.mark.parametrize("metric", [m["name"] for m in SPEC["per_layer"]])
 def test_metric_reader_loads_by_name(metric):
     reader = importlib.import_module(f"bench.metrics.{metric}")

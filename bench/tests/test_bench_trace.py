@@ -88,3 +88,10 @@ def test_no_window_span_is_an_error():
 def test_top_orders_by_time():
     assert tr.top({"a": 1.0, "b": 3.0, "c": 2.0}, 2) == [["b", 3.0],
                                                          ["c", 2.0]]
+
+
+def test_collectives_by_instruction_name():
+    red = tr.reduce(_fixture(), [0, 1])
+    # the all-reduce on device 1, 5-50 ms in the window, over two devices
+    assert red["collectives"] == pytest.approx({"all-reduce": 0.045 / 2})
+    assert tr.reduce(_fixture(), [0])["collectives"] == {}

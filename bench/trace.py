@@ -37,6 +37,10 @@ HOST_SPANS = ("allocate", "local_train", "engine_step", "host_transfer",
               "chunk_dispatch", "eval", "encode", "aggregate",
               "client_update", WINDOW)
 _SCOPE = re.compile(r"feddd_([a-z_]+)")
+# cross-device collectives, by HLO instruction name (``all-reduce.3``,
+# ``all-gather-start.1``, ...)
+_COLLECTIVE = re.compile(
+    r"all-reduce|all-gather|collective-permute|reduce-scatter|all-to-all")
 _SUFFIX = re.compile(r"[.:_]?\d+$")
 _INSTR = re.compile(r"^%?([^\s=]+)\s*=")
 _META = re.compile(r'^\s*(?:ROOT\s+)?%([^\s=]+)\s*=.*op_name="([^"]*)"')
@@ -184,8 +188,9 @@ def span_at(spans: Sequence[Span], t: float) -> str:
 
 def reduce(trace: Trace, devices: Sequence[int]) -> Dict:
     """Busy and idle time inside the ``WINDOW`` span, averaged over
-    ``devices``; device seconds by phase and by operation; host span
-    seconds.  All times in seconds."""
+    ``devices``; device seconds by phase, by operation and of the
+    collectives by instruction name; host span seconds.  All times in
+    seconds."""
     win = [s for s in trace.spans if s.name == WINDOW]
     if not win:
         raise ValueError(f"the trace has no {WINDOW!r} host span")
@@ -197,6 +202,7 @@ def reduce(trace: Trace, devices: Sequence[int]) -> Dict:
     gaps: Dict[str, float] = defaultdict(float)
     by_phase: Dict[str, float] = defaultdict(float)
     by_op: Dict[str, float] = defaultdict(float)
+    coll: Dict[str, float] = defaultdict(float)
     for dev in devices:
         ops = sorted((op for op in trace.ops if op.device == dev
                       and op.end > lo and op.start < hi),
@@ -209,6 +215,8 @@ def reduce(trace: Trace, devices: Sequence[int]) -> Dict:
             ph = phase(op)
             by_phase[ph] += (e - s) / 1e9 / k
             by_op[f"{ph}/{label(op)}"] += (e - s) / 1e9 / k
+            if _COLLECTIVE.search(op.name):
+                coll[_SUFFIX.sub("", op.name)] += (e - s) / 1e9 / k
         merged = union(_clip(op.start, op.end, lo, hi) for op in ops)
         busy += sum(e - s for s, e in merged) / 1e9 / k
         edges = [lo] + [x for iv in merged for x in iv] + [hi]
@@ -221,7 +229,8 @@ def reduce(trace: Trace, devices: Sequence[int]) -> Dict:
         host[sp.name] += (e - s) / 1e9
     return {"window_s": (hi - lo) / 1e9, "busy_s": busy,
             "idle_gaps": dict(gaps), "phases": dict(by_phase),
-            "ops": dict(by_op), "host": dict(host)}
+            "ops": dict(by_op), "collectives": dict(coll),
+            "host": dict(host)}
 
 
 def top(d: Dict[str, float], n: int = 10) -> List[List]:
